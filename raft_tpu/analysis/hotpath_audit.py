@@ -106,6 +106,7 @@ def jaxpr_stats(fn, *args) -> dict:
     input, not a traced value (exactly as ``jax.jit`` statics would
     hold it on the serving path)."""
     import jax
+    from jax.extend import core as jex_core
 
     static = {i for i, a in enumerate(args)
               if a is None or isinstance(a, (int, float, bool, str))}
@@ -125,9 +126,9 @@ def jaxpr_stats(fn, *args) -> dict:
         for v in params.values():
             vals = v if isinstance(v, (tuple, list)) else (v,)
             for x in vals:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jex_core.ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jex_core.Jaxpr):
                     yield x
 
     def walk(jx):

@@ -21,7 +21,7 @@ from ..core.errors import expects
 
 __all__ = ["read_fbin", "write_fbin", "read_ibin", "write_ibin",
            "iter_fbin", "load_dataset", "resolve_lane_dataset",
-           "generate_groundtruth"]
+           "generate_groundtruth", "make_corpus"]
 
 
 def _read_bin(path, dtype) -> np.ndarray:
@@ -184,3 +184,40 @@ def generate_groundtruth(base, queries, k: int = 100,
         outs_d.append(np.asarray(d))
         outs_i.append(np.asarray(i))
     return np.concatenate(outs_d), np.concatenate(outs_i)
+
+
+def make_corpus(n: int, d: int, nq: int, n_clusters: int = 200, seed: int = 0,
+                scale: float = 1.0, intrinsic_d: int = 16, device=None):
+    """SIFT-like synthetic corpus, generated on device from ``seed``.
+
+    A low-intrinsic-dimension clustered mixture: points live near a
+    random ``intrinsic_d``-dim subspace (cluster centers and
+    within-cluster spread both low-rank) plus small ambient noise, so
+    neighborhoods straddle IVF partition boundaries the way SIFT's do.
+    Queries are FRESH mixture samples, not perturbed corpus rows.
+    ``device``: where the arrays are made (default: JAX's default
+    device). Returns ``(data (n, d), queries (nq, d))`` f32."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def gen(key):
+        kw, kc, kx, ka, kq, kp, ke, kf = jax.random.split(key, 8)
+        w = jax.random.normal(kw, (intrinsic_d, d), jnp.float32)
+        w = w / jnp.linalg.norm(w, axis=1, keepdims=True)
+        centers_z = jax.random.normal(kc, (n_clusters, intrinsic_d),
+                                      jnp.float32) * scale
+        assign = jax.random.randint(ka, (n,), 0, n_clusters)
+        z = centers_z[assign] + jax.random.normal(kx, (n, intrinsic_d),
+                                                  jnp.float32)
+        data = z @ w + 0.1 * jax.random.normal(ke, (n, d), jnp.float32)
+        qassign = jax.random.randint(kq, (nq,), 0, n_clusters)
+        qz = centers_z[qassign] + jax.random.normal(kp, (nq, intrinsic_d),
+                                                    jnp.float32)
+        queries = qz @ w + 0.1 * jax.random.normal(kf, (nq, d), jnp.float32)
+        return data, queries
+
+    key = jax.random.PRNGKey(seed)
+    if device is not None:
+        key = jax.device_put(key, device)
+    return jax.block_until_ready(gen(key))

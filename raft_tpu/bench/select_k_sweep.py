@@ -4,11 +4,7 @@ Produces the recorded measurement behind ``select_k``'s single-engine
 design note (the measured analog of the reference's per-arch
 ``choose_select_k_algorithm`` table, matrix/detail/select_k-inl.cuh:48-72):
 every point runs ``tune_select_k`` — per-call-blocked medians — purely as
-a calibration record (nothing dispatches on it; every algo name maps to
-the same engine). The historical sweep (bench_select_k_sweep.json at the
-repo root) measured a masked-input "radix" pre-filter tying plain top_k
-within dispatch noise at every point, which is why select_k ships a
-single sort-based engine (see matrix/select_k.py).
+a calibration record (nothing dispatches on it).
 
 Run: ``python -m raft_tpu.bench.select_k_sweep [out.json]`` on the target
 device.
@@ -50,7 +46,7 @@ def run(out_path: str | None = None) -> dict:
         "device": f"{dev.platform}:{getattr(dev, 'device_kind', '?')}",
         "methodology": ("tune_select_k: per-call-blocked median of 5, "
                         "per-rep input perturb + output chain "
-                        "(anti replay-cache)"),
+                        "(each rep distinct work)"),
         "results": results,
     }
     if out_path:
@@ -60,6 +56,6 @@ def run(out_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
-    out = sys.argv[1] if len(sys.argv) > 1 else "bench_select_k_sweep.json"
+    out = sys.argv[1] if len(sys.argv) > 1 else None
     doc = run(out)
     print(json.dumps(doc))

@@ -137,13 +137,9 @@ def init_distributed(coordinator_address: Optional[str] = None,
 def local_mesh(n_devices: Optional[int] = None, axis: str = "shard",
                platform: Optional[str] = None) -> Mesh:
     """1-D mesh over local devices (the LocalCUDACluster-style test path).
-
-    Falls back to CPU devices when the default platform has too few (the
-    single-TPU-chip + 8-virtual-CPU development setup).
-    """
+    Raises when ``platform`` (default: JAX's default) has fewer than
+    ``n_devices`` devices — it never swaps in another platform's."""
     devices = jax.devices(platform) if platform else jax.devices()
-    if n_devices is not None and len(devices) < n_devices:
-        devices = jax.devices("cpu")
     if n_devices is not None:
         expects(len(devices) >= n_devices, "need %d devices, have %d",
                 n_devices, len(devices))

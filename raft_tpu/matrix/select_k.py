@@ -18,8 +18,7 @@ TPU design, two engines (mirroring the reference's two families):
 
 ``RADIX`` remains an alias: the radix/AIR histogram engine does not
 transfer to TPU (histograms lower to serialized scatters or FLOP-heavy
-one-hot contractions; the r3 sweep in bench_select_k_sweep.json showed
-no winnable shape). ``AUTO`` picks KPASS on TPU for f32 rows with
+one-hot contractions). ``AUTO`` picks KPASS on TPU for f32 rows with
 k ≤ 64 and 512 ≤ n ≤ 4096, TOPK otherwise. The column cap is a VMEM
 bound, not a tuning choice: the kernel keeps ~5 live (128, n) f32/i32
 planes on the scoped-VMEM stack, and measured compile-time OOMs on v5e
@@ -82,18 +81,21 @@ def _kpass_kernel(x_ref, ov_ref, oi_ref, *, k: int, kp: int, n: int,
     x = x_ref[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (128, n), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (128, kp), 1)
-    alive0 = col < n_real
+    # the alive mask is carried as int32: Mosaic cannot legalize an
+    # scf.for (the k > 32 fori_loop) that carries an i1 vector
+    alive0 = (col < n_real).astype(jnp.int32)
 
     def extract(t, state):
         alive, nv, ni = state
-        masked = jnp.where(alive, x, jnp.inf)
+        live = alive != 0
+        masked = jnp.where(live, x, jnp.inf)
         best = jnp.min(masked, axis=1, keepdims=True)
-        pos = jnp.min(jnp.where(alive & (masked <= best), col, _INT_BIG),
+        pos = jnp.min(jnp.where(live & (masked <= best), col, _INT_BIG),
                       axis=1, keepdims=True)
         at = col == pos
         nv = jnp.where(lane == t, best, nv)
         ni = jnp.where(lane == t, pos, ni)
-        return alive & ~at, nv, ni
+        return jnp.where(at, 0, alive), nv, ni
 
     state = (alive0, jnp.full((128, kp), jnp.inf, jnp.float32),
              jnp.full((128, kp), -1, jnp.int32))

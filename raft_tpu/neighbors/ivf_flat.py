@@ -123,8 +123,7 @@ class Index:
     def tree_flatten(self):
         # the pallas scan-prep cache travels WITH the index so a jitted
         # function can take the index as an ARGUMENT (closure-baked index
-        # arrays become HLO constants whose serialized size exceeds
-        # remote-compile request limits at memory scale)
+        # arrays would become index-sized HLO constants)
         cache = getattr(self, "_scan_pad", None)
         cache_leaves = None if cache is None else tuple(cache[1:])
         leaves = (self.data, self.data_norms, self.source_ids,

@@ -68,24 +68,8 @@ _INT_BIG = 2**30  # sentinel column id, larger than any real lane index
 _ROUNDS = 2
 
 
-def _compiler_params(dimension_semantics):
-    """Version-compat TPU compiler params (resilience: API skew must
-    degrade to the equivalent spelling, not crash the kernel path).
-    Newer jax spells it ``pltpu.CompilerParams`` with a
-    ``GridDimensionSemantics`` enum; 0.4.x uses ``TPUCompilerParams``
-    with plain 'parallel'/'arbitrary' strings."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is not None:
-        sem = getattr(pltpu, "GridDimensionSemantics", None)
-        dims = (tuple(getattr(sem, s.upper()) for s in dimension_semantics)
-                if sem is not None and hasattr(sem, "PARALLEL") else None)
-        return cls(dimension_semantics=dims)
-    return pltpu.TPUCompilerParams(
-        dimension_semantics=tuple(dimension_semantics))
-
-
 def _pick_tiles(dim_p: int, k: int, itemsize: int = 4) -> Tuple[int, int]:
-    """(query-tile, dataset-tile) sizes under a ~12 MB VMEM working set.
+    """(query-tile, dataset-tile) sizes under v5e's 16 MB scoped VMEM.
 
     Defaults target v5e-class VMEM; override with
     ``RAFT_TPU_FUSED_TILES=tm,tn`` when sweeping other generations.
@@ -112,16 +96,16 @@ def _pick_tiles(dim_p: int, k: int, itemsize: int = 4) -> Tuple[int, int]:
         tm = max(8, (tm // 8) * 8)
         tn = max(128, (tn // 128) * 128)
         return tm, tn
-    if dim_p <= 256:
-        tm, tn = 512, 1024
-    elif dim_p <= 512:
-        tm, tn = 512, 512
-    else:
-        tm, tn = 256, 512
+    # tm=256: the (tm, tn) distance tile and its reduce temporaries
+    # dominate scoped VMEM — tm=512 needed 19.0 MB (f32, d128) and
+    # 21.1 MB (d512) against v5e's 16 MB scoped limit
+    tm, tn = 256, (1024 if dim_p <= 256 else 512)
     if itemsize <= 2 and dim_p <= 512:
         tn *= 2
-    if k > 64:
-        tm = max(tm // 2, 128)
+    if k > 128:
+        # kp=256 doubles the (tm, kp) merge state: tm=256 at k=129 needs
+        # more than v5e's scoped VMEM (a v5e compile refused it)
+        tm = 128
     return tm, tn
 
 
@@ -351,7 +335,8 @@ def _fused_knn_padded(q, d, dn, pen, sc, k: int, metric: str,
             pltpu.VMEM((tm, kp), jnp.float32),
             pltpu.VMEM((tm, kp), jnp.int32),
         ],
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=flops,
             bytes_accessed=int(q.size * 4 + d.size * d.dtype.itemsize

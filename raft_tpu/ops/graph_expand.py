@@ -42,9 +42,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import round_up_to
 
-__all__ = ["graph_expand", "edge_tile_widen", "score_dim"]
+__all__ = ["graph_expand", "edge_tile_widen", "score_dim", "lane_rows"]
 
 _INT_BIG = 2**30
+
+
+def lane_rows(x: jax.Array) -> jax.Array:
+    """Pad the minor (edge) axis of a per-node row array — aux
+    ``(n, 2, deg_p)``, graph/penalty ``(n, 1, deg_p)`` — to the 128-lane
+    tile. Mosaic refuses a per-node DMA slice whose minor extent is not
+    a lane multiple (deg64 rows were refused on v5e), so the kernels
+    stream lane-padded rows and read their first ``deg_p`` lanes.
+    A no-op on rows that are already aligned (``prepare_traversal``
+    stores them so; only ad-hoc callers pay the copy)."""
+    pad = round_up_to(x.shape[-1], 128) - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def _pick_pq(width: int) -> int:
@@ -149,9 +163,9 @@ def _kernel(pids_ref, q_ref, vecs_hbm, aux_hbm, *rest, P: int, P_q: int,
     for c in copies:
         c.wait()
     V = vtile[:]                                     # (P, deg_p, dim_p)
-    A = atile[:]                                     # (P, 2, deg_p)
-    scales = A[:, 0, :]                              # (P, deg_p)
-    vnorm = A[:, 1, :]                               # ||dequant v||²
+    # aux/pen rows are lane-padded (see lane_rows): read the deg_p edges
+    scales = atile[:, 0, :deg_p]                     # (P, deg_p)
+    vnorm = atile[:, 1, :deg_p]                      # ||dequant v||²
 
     # route each parent its own query row with a one-hot matmul — parent
     # j of the step belongs to query j // width — then score per parent
@@ -176,7 +190,7 @@ def _kernel(pids_ref, q_ref, vecs_hbm, aux_hbm, *rest, P: int, P_q: int,
     else:                                            # "ip": min-space -dot
         dist = -cross
     if with_pen:
-        dist = dist + ptile[:].reshape(P, deg_p)
+        dist = dist + ptile[:, 0, :deg_p]
     col = jax.lax.broadcasted_iota(jnp.int32, (P, deg_p), 1)
     dist = jnp.where(col < degree, dist, jnp.inf)    # pad edges out
 
@@ -213,6 +227,7 @@ def _expand_padded(pids, q, vecs, aux, pen, cbm, cbscl, k_out: int,
                    interpret: bool, with_pen: bool, mode: str):
     m_pad, dim_p = q.shape
     n, deg_p, store_w = vecs.shape
+    lane_w = aux.shape[2]
     P = P_q * width
     kp = round_up_to(k_out, 128)
     grid = (m_pad // P_q,)
@@ -240,10 +255,10 @@ def _expand_padded(pids, q, vecs, aux, pen, cbm, cbscl, k_out: int,
         args.append(pen)
     scratch = [
         pltpu.VMEM((P, deg_p, store_w), vecs.dtype),
-        pltpu.VMEM((P, 2, deg_p), jnp.float32),
+        pltpu.VMEM((P, 2, lane_w), jnp.float32),
     ]
     if with_pen:
-        scratch.append(pltpu.VMEM((P, 1, deg_p), jnp.float32))
+        scratch.append(pltpu.VMEM((P, 1, lane_w), jnp.float32))
     scratch.append(pltpu.SemaphoreType.DMA((3, P)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -285,11 +300,11 @@ def graph_expand(
     parents: jax.Array,          # (m, width) int32 parent node ids
     queries: jax.Array,          # (m, dim) f32
     vecs: jax.Array,             # (n, deg_p, W) int8 | bf16 | u8 edge store
-    aux: jax.Array,              # (n, 2, deg_p) f32: [scales, dequant norms]
+    aux: jax.Array,              # (n, 2, ≥deg_p) f32: [scales, dequant norms]
     k_out: int,
     metric: str = "l2",
     degree: Optional[int] = None,
-    pen: Optional[jax.Array] = None,   # (n, deg_p) f32: +inf excludes edge
+    pen: Optional[jax.Array] = None,   # (n, ≥deg_p) f32: +inf excludes edge
     interpret: Optional[bool] = None,
     mode: str = "dense",
     cbm: Optional[jax.Array] = None,     # pq: (pq_dim*book, dim_p)
@@ -327,9 +342,11 @@ def graph_expand(
     pids = jnp.pad(pids, ((0, m_pad - m), (0, 0))).reshape(-1)
     # None rides through jit as an empty pytree; the kernel only takes a
     # pen operand when with_pen
-    pen3 = pen.reshape(n, 1, deg_p) if pen is not None else None
+    pen3 = (lane_rows(pen.reshape(n, 1, pen.shape[-1]))
+            if pen is not None else None)
 
-    vals, epos = _expand_padded(pids, q, vecs, aux, pen3, cbm, cb_scale,
+    vals, epos = _expand_padded(pids, q, vecs, lane_rows(aux), pen3, cbm,
+                                cb_scale,
                                 k_out, metric, width, degree, P_q,
                                 interpret, pen is not None, mode)
     vals = vals.reshape(m_pad, width, -1)[:m, :, :k_out]

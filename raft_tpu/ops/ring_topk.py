@@ -97,10 +97,11 @@ ALL_ENGINES = ENGINES + ("hier",)
 MERGE_SITE = "sharded.ring_topk"
 
 _INT_BIG = 2 ** 30
-# conservative VMEM budget for the full-residency ring kernel: running
-# state (3 planes) + double-buffered comm slots (2×2 planes) + in/out
-# (4 planes) + fold temporaries ≈ 12 live (mp, kp)/(mp, 2kp) f32 planes
-_VMEM_CELL_CAP = 256 * 1024
+# VMEM budget for the full-residency ring kernel: running state (3
+# planes) + double-buffered comm slots (2×2 planes) + in/out (4 planes)
+# + fold temporaries. Compiled for v5e (tests/test_tpu_compile.py):
+# (512, 128) cells fit its 16 MB scoped VMEM, (1024, 128) do not
+_VMEM_CELL_CAP = 64 * 1024
 
 
 # --------------------------------------------------------------------------
@@ -256,9 +257,10 @@ def _vmem_fold(cd, cp, cg, k: int, kp: int, extra=()):
     def extract(t, state):
         alive, nd, npos, ng = state[:4]
         nex = state[4:]
-        masked = jnp.where(alive, cd, jnp.inf)
+        live = alive != 0
+        masked = jnp.where(live, cd, jnp.inf)
         best = jnp.min(masked, axis=1, keepdims=True)
-        cand = alive & (masked <= best)
+        cand = live & (masked <= best)
         bpos = jnp.min(jnp.where(cand, cp, _INT_BIG), axis=1, keepdims=True)
         at = cand & (cp == bpos)
         # position uniqueness makes `at` single-cell among real
@@ -273,10 +275,12 @@ def _vmem_fold(cd, cp, cg, k: int, kp: int, extra=()):
                       jnp.min(jnp.where(at, ce, jnp.iinfo(jnp.int32).max),
                               axis=1, keepdims=True), ne)
             for ce, ne in zip(extra, nex))
-        return (alive & ~at, jnp.where(hit, best, nd),
+        return (jnp.where(at, 0, alive), jnp.where(hit, best, nd),
                 jnp.where(hit, bpos, npos), jnp.where(hit, g, ng)) + exs
 
-    state = (jnp.ones(cd.shape, jnp.bool_),
+    # alive rides as int32: Mosaic cannot legalize an scf.for (the
+    # k > 32 fori_loop) carrying an i1 vector
+    state = (jnp.ones(cd.shape, jnp.int32),
              jnp.full((m, kp), jnp.inf, jnp.float32),
              jnp.full((m, kp), _INT_BIG, jnp.int32),
              jnp.full((m, kp), -1, jnp.int32))
@@ -427,7 +431,7 @@ def _ring_pallas(d, gid, k: int, select_min: bool, axis: str, p: int):
             pltpu.SemaphoreType.DMA((2,)),          # recv sems (d, gid)
             pltpu.SemaphoreType.REGULAR,            # slot-free credits
         ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=7),
+        compiler_params=pltpu.CompilerParams(collective_id=7),
     )(kd, g)
     out_d = out_d[:m, :k]
     return (out_d if select_min else -out_d), out_g[:m, :k]
@@ -533,6 +537,15 @@ def _mesh_device(mesh_or_device):
     return devs.flat[0] if devs is not None else mesh_or_device
 
 
+# the Pallas ring kernel halted a v5e 2x2 on its first call ("Semaphore
+# (scratch argument 7) has a nonzero value upon exit from a Mosaic
+# kernel", PR 21 chip run): no default, race or recorded verdict picks
+# it, and an explicit ask on a TPU mesh raises
+_RING_PALLAS_HALTS_TPU = ("the ring_pallas merge halts a TPU v5e 2x2 "
+                          "(a DMA semaphore left nonzero at kernel exit); "
+                          "use merge_engine='allgather' or 'ring'")
+
+
 def ring_capable(m: int, k: int, backend: Optional[str] = None) -> bool:
     """Whether the Pallas ring kernel can run this shape: a real TPU
     (remote DMA has no interpret emulation on this jax) and the
@@ -605,18 +618,16 @@ def resolve_engine(m: int, k: int, p: int, dtype=jnp.float32,
                 "unknown sharded merge engine %r (env/param); one of %s",
                 eng, ENGINES + ("auto",))
         if eng != "auto":
+            expects(eng != "ring_pallas" or platform != "tpu",
+                    _RING_PALLAS_HALTS_TPU)
             if eng == "ring_pallas" and not ring_capable(m, k, platform):
                 return "ring"
             return eng
     from . import autotune
 
     hit = autotune.lookup(_bucket(m, k, p, dtype, mesh))
-    if hit in ENGINES:
-        if hit == "ring_pallas" and not ring_capable(m, k, platform):
-            return "ring"
+    if hit in ENGINES and hit != "ring_pallas":
         return hit
-    if ring_capable(m, k, platform):
-        return "ring_pallas"
     return "allgather"
 
 
@@ -641,9 +652,7 @@ def tune_merge(mesh, m: int, k: int, select_min: bool = True,
     dd = jax.device_put(d, NamedSharding(mesh, P(axis, None, None)))
     gg = jax.device_put(gid, NamedSharding(mesh, P(axis, None, None)))
 
-    names = engines or [
-        e for e in ENGINES if e != "ring_pallas"
-        or ring_capable(m, k, _mesh_device(mesh).platform)]
+    names = engines or [e for e in ENGINES if e != "ring_pallas"]
 
     def make(eng):
         def body(ds, gs):

@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..comms import AxisComms
 from ..core import faults
+from ..core.resources import workspace_chunk_bytes
 from ..core.errors import ShardsDownError, expects
 from ..distance.distance_types import DistanceType, canonical_metric, is_min_close
 from ..neighbors import cagra, ivf_flat, ivf_pq
@@ -927,6 +928,13 @@ def search_ivf_pq(index: ShardedIvfPq, queries, k: int,
 
     mask = filter.to_mask() if filter is not None else None
     has_filter = mask is not None
+    # queries per gather pass, by ivf_pq.search's per-query workspace
+    # bound: the whole batch in one pass (candidates × pq_dim gather +
+    # the per-probe LUTs) needs over 100 GB at 10k queries × 1M rows/shard
+    pq_dim = index.codes.shape[2]
+    per_q = (max_rows * pq_dim * 8
+             + n_probes * pq_dim * (1 << index.pq_bits) * 4)
+    chunk = max(1, workspace_chunk_bytes(res) // per_q)
 
     def make_local():
         def local(codes, gids, centers, books, rots, offsets, sizes, okf,
@@ -935,9 +943,20 @@ def search_ivf_pq(index: ShardedIvfPq, queries, k: int,
             shard = ivf_pq.Index(
                 codes[0], gids[0], centers[0], books[0], rots[0],
                 dummy_off, mt, index.pq_bits, index.codebook_kind)
-            d, i = ivf_pq._search_chunk(shard, qq, k, n_probes, max_rows,
-                                        offsets[0], sizes[0], mb,
-                                        sp.lut_dtype)
+
+            def one(qc):
+                return ivf_pq._search_chunk(shard, qc, k, n_probes,
+                                            max_rows, offsets[0], sizes[0],
+                                            mb, sp.lut_dtype)
+
+            m = qq.shape[0]
+            if m <= chunk:
+                d, i = one(qq)
+            else:
+                nc = cdiv(m, chunk)
+                qs = jnp.pad(qq, ((0, nc * chunk - m), (0, 0)))
+                d, i = jax.lax.map(one, qs.reshape(nc, chunk, -1))
+                d, i = d.reshape(-1, k)[:m], i.reshape(-1, k)[:m]
             i = jnp.where(okf[0, 0], i, -1)     # dead-shard containment
             bad = jnp.inf if select_min else -jnp.inf
             d = jnp.where(i >= 0, d, bad)       # padded rows carry id -1
@@ -957,7 +976,7 @@ def search_ivf_pq(index: ShardedIvfPq, queries, k: int,
         arrays.append(mask)
     statics = (("np", n_probes), ("mr", max_rows), ("mt", mt.name),
                ("lut", np.dtype(sp.lut_dtype).name), ("f", has_filter),
-               ("b", index.pq_bits),
+               ("b", index.pq_bits), ("qc", chunk),
                ("ck", getattr(index.codebook_kind, "name",
                               index.codebook_kind)))
     d, i = _merged_shard_search(index, "ivf_pq", make_local, in_specs,

@@ -163,12 +163,7 @@ def dryrun(n_devices: int, ring_check: bool = True) -> None:
     cross-check (a second full search compile, ~4 s on the CPU mesh):
     the driver artifact keeps it; tier-1 covers the same path in
     tests/test_ring_topk.py."""
-    devices = jax.devices()
-    if len(devices) < n_devices:
-        # single real TPU chip under the driver: fall back to the virtual
-        # CPU devices provided by --xla_force_host_platform_device_count
-        devices = jax.devices("cpu")
-    devices = devices[:n_devices]
+    devices = jax.devices()[:n_devices]
     expects(len(devices) == n_devices,
             "need %d devices, have %d", n_devices, len(devices))
     mesh = Mesh(np.array(devices), (AXIS,))

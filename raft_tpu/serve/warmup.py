@@ -9,13 +9,14 @@ through the live search closure at every (query-bucket × k-bucket)
 shape and blocks on the results, so steady-state serving hits only
 cached executables.
 
-The matching measurement wraps ``jax._src.compiler.backend_compile`` —
-the single funnel both the jit cache-miss path and
-``compile_or_get_cached`` route through on jax 0.4.x — and comes in two
+The matching measurement listens on JAX's public monitoring stream:
+every executable JAX builds — a backend compile or a persistent-cache
+load, both through ``compile_or_get_cached`` — records one
+``/jax/core/compile/backend_compile_duration`` event. It comes in two
 layers:
 
-* :func:`install_recompile_watch` patches the funnel ONCE per process
-  (idempotent) with a spy that (a) increments the always-on
+* :func:`install_recompile_watch` registers ONE listener per process
+  (idempotent) that (a) increments the always-on
   ``serve.compiles`` total, and (b) for compiles carrying a
   non-warmup :func:`compile_context` label (the batcher sets its
   ``<name>:<rows>x<k>`` shape bucket around every dispatch) — i.e. a
@@ -54,32 +55,32 @@ class CompileCounter:
         self.count = 0
 
 
-# persistent watch state: original funnel + live subscriber counters
+# persistent watch state: live subscriber counters
 _watch_lock = threading.Lock()
 _watch_subs: List[CompileCounter] = []
 _watch_installed = False
 _ctx = threading.local()        # .label (str), .warmup (bool)
 
+# the event JAX records once per executable it builds (jax._src.dispatch
+# BACKEND_COMPILE_EVENT; the name is part of the public monitoring stream)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-def _compile_funnel():
-    """The versioned private compile funnel (raises RuntimeError if this
-    jax moved it — a vacuous zero would silently gut every recompile
-    assertion, and callers degrading gracefully catch RuntimeError)."""
-    try:
-        from jax._src import compiler as _compiler  # versioned private API
-    except ImportError as e:
-        raise RuntimeError(
-            f"jax._src.compiler not importable on jax {jax.__version__} "
-            f"({e}); update serve.warmup to this version's compile "
-            "funnel") from e
 
-    orig = getattr(_compiler, "backend_compile", None)
-    if orig is None:
+def _register_listener(callback) -> None:
+    """Subscribe ``callback(event, duration_s, **kw)`` to JAX's duration
+    events (raises RuntimeError if this jax has no such listener API — a
+    vacuous zero would silently gut every recompile assertion, and
+    callers degrading gracefully catch RuntimeError)."""
+    from jax import monitoring
+
+    register = getattr(monitoring,
+                       "register_event_duration_secs_listener", None)
+    if register is None:
         raise RuntimeError(
-            "jax._src.compiler.backend_compile not found on jax "
-            f"{jax.__version__}; update serve.warmup to this version's "
-            "compile funnel")
-    return _compiler, orig
+            "jax.monitoring.register_event_duration_secs_listener not "
+            f"found on jax {jax.__version__}; update serve.warmup to this "
+            "version's compile event")
+    register(callback)
 
 
 @contextlib.contextmanager
@@ -97,15 +98,16 @@ def compile_context(label: str, warmup: bool = False):
 
 
 def install_recompile_watch() -> None:
-    """Install the persistent compile spy (idempotent; see module
-    docstring). Raises RuntimeError when the compile funnel moved."""
+    """Install the persistent compile listener (idempotent; see module
+    docstring). Raises RuntimeError when this jax has no listener API."""
     global _watch_installed
     with _watch_lock:
         if _watch_installed:
             return
-        _compiler, orig = _compile_funnel()
 
-        def _spy(*args, **kwargs):
+        def _on_event(event, duration_s, **_kw):
+            if event != _COMPILE_EVENT:
+                return
             with _watch_lock:
                 subs = list(_watch_subs)
             for c in subs:
@@ -133,9 +135,8 @@ def install_recompile_watch() -> None:
                     _metrics.counter("serve.recompiles").inc()
             except Exception:  # noqa: BLE001 - telemetry must not break compiles
                 pass
-            return orig(*args, **kwargs)
 
-        _compiler.backend_compile = _spy
+        _register_listener(_on_event)
         _watch_installed = True
 
 
@@ -144,7 +145,7 @@ def count_compilations():
     """Count XLA compilations during the block (yields a
     :class:`CompileCounter`). Installs the persistent watch on first use
     and subscribes to it — nested/concurrent blocks each see every
-    compile. Raises if this jax version moved the compile funnel."""
+    compile. Raises if this jax version has no compile listener API."""
     install_recompile_watch()
     counter = CompileCounter()
     with _watch_lock:

@@ -7,6 +7,7 @@ blocks and padded layouts.
 from __future__ import annotations
 
 import math
+import os
 
 __all__ = [
     "cdiv",
@@ -19,6 +20,7 @@ __all__ = [
     "pad_to",
     "run_query_chunks",
     "shard_map_compat",
+    "use_compile_cache",
     "LANES",
     "SUBLANES_F32",
     "SUBLANES_BF16",
@@ -127,35 +129,36 @@ def run_query_chunks(fn, q, chunk: int, res=None):
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across jax versions (resilience: a version skew
-    must degrade to the equivalent API, not crash the sharded path).
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; the promotion
-    window spelled the kwarg ``check_rep``; older releases only have
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``. The kwarg
-    is feature-tested, not version-guessed."""
+    """``jax.shard_map`` with its replication check spelled once
+    (``check_vma``)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def in_jax_trace() -> bool:
     """True when called during a jax trace (jit/vmap/...). Used to gate
     side-effecting caches: storing traced arrays on a Python object leaks
     tracers out of the transformation."""
-    try:
-        from jax._src.core import trace_state_clean
+    from jax._src.core import trace_state_clean
 
-        return not trace_state_clean()
-    except ImportError:  # fallback probe: ops under a trace yield Tracers
-        import jax
-        import jax.numpy as jnp
+    return not trace_state_clean()
 
-        return isinstance(jnp.zeros(()) + 0, jax.core.Tracer)
+
+def use_compile_cache(root: str) -> str:
+    """Place JAX's persistent compilation cache before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is where the cache lives:
+    JAX reads it itself and nothing is set in code. Otherwise the cache
+    sits at a fixed ``.jax_cache/`` under ``root`` (the checkout) — a
+    fixed path, because the path is part of the cache key. Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

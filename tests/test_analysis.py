@@ -460,12 +460,11 @@ class TestServeTreeVerdicts:
                         "raft_tpu/serve/qcache.py"):
                 assert mod in rels, f"{mod} fell out of the scan list"
 
-    def test_fragile_repeat_is_baselined_not_new(self, tree_run):
-        """The documented ivf_pq pltpu.repeat quirk is visible to the
-        gate (it must not silently disappear while the kernel still
-        calls repeat) and is baselined, pending real-TPU adjudication."""
+    def test_no_fragile_repeat_in_tree(self, tree_run):
+        """The ivf_pq decode's pltpu.repeat, once baselined pending a
+        chip, returned recall 0.07 on a v5e and was replaced: no kernel
+        in the tree calls repeat, and none is baselined."""
         findings, _ = tree_run
-        rep = [f for f in findings if f.rule == "fragile-repeat"]
-        assert len(rep) == 1
-        assert rep[0].path == "raft_tpu/ops/ivf_pq_scan.py"
-        assert rep[0].key in set(analysis.load_baseline())
+        assert [f for f in findings if f.rule == "fragile-repeat"] == []
+        assert not [k for k in analysis.load_baseline()
+                    if k.startswith("fragile-repeat::")]

@@ -192,8 +192,7 @@ class TestCagra:
     def test_index_as_jit_argument(self, built_index, dataset, queries):
         """The pytree carries the traversal caches and seed set
         byte-identical, so jitted functions can take the index as an
-        ARGUMENT (baked closure constants exceed remote-compile limits
-        at memory scale)."""
+        ARGUMENT (not as index-sized baked closure constants)."""
         import jax
 
         cagra.prepare_search(built_index)

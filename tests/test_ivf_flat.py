@@ -269,8 +269,8 @@ class TestIvfFlat:
 
     def test_index_as_jit_argument(self, built_index, queries):
         """The pytree carries the aligned-DMA pad cache byte-identical,
-        so jitted functions can take the index as an ARGUMENT (baked
-        closure constants exceed remote-compile limits at 500k rows)."""
+        so jitted functions can take the index as an ARGUMENT (not as
+        index-sized baked closure constants)."""
         import jax
 
         ivf_flat.prepare_scan(built_index)

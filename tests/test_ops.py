@@ -142,16 +142,6 @@ class TestIvfScanParity:
             np.testing.assert_allclose(np.asarray(dp), np.asarray(dx),
                                        rtol=5e-2, atol=5e-1)
 
-    @pytest.mark.xfail(
-        strict=False, run=False,
-        reason="known jax-0.4.37 interpret divergence: pltpu.repeat is "
-               "ELEMENT-wise (np.repeat) under the CPU interpreter while "
-               "the ivf_pq one-hot decode requires tiling semantics "
-               "(see ivf_pq_scan.make_cb_matrix), scrambling the decode "
-               "for every lut_mode; expected to pass on the Mosaic "
-               "lowering (tiling), pending first real-TPU validation. "
-               "run=False: environment-pinned, and the run only burns "
-               "the tight tier-1 budget")
     def test_ivf_pq_pallas_matches_xla(self):
         import jax.numpy as jnp
 
@@ -194,16 +184,6 @@ class TestIvfScanParity:
         np.testing.assert_allclose(np.asarray(dp), np.asarray(dx),
                                    rtol=1e-3, atol=1e-3)
 
-    @pytest.mark.xfail(
-        strict=False, run=False,
-        reason="known jax-0.4.37 interpret divergence: pltpu.repeat is "
-               "ELEMENT-wise (np.repeat) under the CPU interpreter while "
-               "the ivf_pq one-hot decode requires tiling semantics "
-               "(see ivf_pq_scan.make_cb_matrix), scrambling the decode "
-               "for every lut_mode; expected to pass on the Mosaic "
-               "lowering (tiling), pending first real-TPU validation. "
-               "run=False: environment-pinned, and the run only burns "
-               "the tight tier-1 budget")
     def test_ivf_pq_pallas_filter_excludes(self):
         import jax.numpy as jnp
 

@@ -251,6 +251,21 @@ class TestEngineResolution:
         assert ring_topk.resolve_engine(
             8, 5, 4, override="ring_pallas") == "ring"
 
+    def test_tpu_never_resolves_the_ring_kernel(self, monkeypatch):
+        # it halted a v5e 2x2 (PR 21): a TPU mesh defaults to allgather,
+        # ignores a recorded ring_pallas verdict, and refuses an ask
+        from types import SimpleNamespace
+
+        from raft_tpu.ops import autotune
+
+        tpu = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        assert ring_topk.resolve_engine(8, 5, 4, mesh=tpu) == "allgather"
+        monkeypatch.setattr(autotune, "lookup", lambda key: "ring_pallas")
+        assert ring_topk.resolve_engine(8, 5, 4, mesh=tpu) == "allgather"
+        with pytest.raises(Exception, match="halts a TPU"):
+            ring_topk.resolve_engine(8, 5, 4, override="ring_pallas",
+                                     mesh=tpu)
+
     def test_note_fallback_reports_to_ops_surface(self):
         from raft_tpu.serve import metrics
 

@@ -146,6 +146,22 @@ class TestShardedIvfPq:
         # the sharded merge must stay at that quality level
         assert r >= 0.5, f"sharded ivf_pq recall {r}"
 
+    def test_query_chunks_match_one_pass(self, queries, pq_index16,
+                                         monkeypatch):
+        """A batch past the workspace budget is searched in chunks of
+        queries (the tail padded) with the one-pass answer."""
+        from raft_tpu.neighbors import ivf_pq
+
+        sp = ivf_pq.SearchParams(n_probes=16)
+        d1, i1 = sharded_ann.search_ivf_pq(pq_index16, queries, 10, sp)
+        per_q = (pq_index16.max_rows(16) * 8 * 8 + 16 * 8 * 256 * 4)
+        monkeypatch.setattr(sharded_ann, "workspace_chunk_bytes",
+                            lambda res: 7 * per_q)     # 50 = 7 × 7 + 1
+        d2, i2 = sharded_ann.search_ivf_pq(pq_index16, queries, 10, sp)
+        assert np.mean(np.asarray(i1) == np.asarray(i2)) > 0.99
+        np.testing.assert_allclose(np.asarray(d2), np.asarray(d1),
+                                   rtol=1e-5, atol=1e-5)
+
     # tier-1 wall (PR 8 pays for the quality-observability suite):
     # uneven-row stacking/rebasing stays tier-1 via the ivf_flat and
     # cagra uneven tests through the same merge chokepoint, and the
