@@ -1,30 +1,29 @@
 """Tracing/profiling ranges: TPU-native analog of the reference's NVTX layer.
 
 Reference: raft/core/nvtx.hpp:84 (RAII ``nvtx::range`` pushed at every public
-entry point, compiled out unless RAFT_NVTX). Here ranges map onto
-``jax.profiler.TraceAnnotation`` so they show up in TPU profiler/Perfetto
-traces; a module-level switch keeps them zero-cost when disabled.
+entry point). Here every range enters a ``jax.profiler.TraceAnnotation``, so
+it shows up on the host thread's line of an operator's profile (TensorBoard,
+Perfetto, ``jax.profiler.ProfileData``) on the same clock as the device's
+ops. No switch gates it: with no profiler collecting, an annotation costs
+about half a microsecond, and names follow ``raft_tpu::<module>::<step>``.
 
 A span *timer* can additionally be installed with :func:`set_timer`
 (``raft_tpu.serve.metrics.enable_span_metrics`` does): every range and
 annotated call then reports its wall duration under its span name,
-giving the serving metrics per-stage latency histograms for free. The
-timer is independent of the profiler switch — metrics collection must
-not require Perfetto tracing to be on — and both default off, keeping
-the probes one ``is None`` check on the hot path.
+giving the serving metrics per-stage latency histograms for free. A
+range given ``out=`` also writes its duration into that dict, which is
+how the serving batcher times its stages once, from its own spans.
 
 Request-lifecycle layer (docs/observability.md): the serving runtime
 stamps every request with a **trace ID** (:func:`new_trace_id`) and
 binds the active IDs around dispatch (:func:`bind_trace`), so anything
 that fires mid-dispatch — a guarded demotion, an injected fault, an XLA
 recompile (all recorded in :mod:`raft_tpu.core.events`) — is stamped
-with the requests it hit. :func:`child_span` times one stage of a
-request (queue wait, pad, dispatch, ...); sampled requests additionally
-log their full stage decomposition into a bounded in-process **span
-log** (:func:`log_spans` / :func:`recent_spans`). Sampling is governed
-by ``RAFT_TPU_TRACE_SAMPLE`` (:func:`sample_rate`, validated float in
-[0, 1], default 0 = off): with it off and no timer installed, every
-probe site is a single ``is None``/falsy check.
+with the requests it hit. Sampled requests additionally log their full
+stage decomposition into a bounded in-process **span log**
+(:func:`log_spans` / :func:`recent_spans`). Sampling is governed by
+``RAFT_TPU_TRACE_SAMPLE`` (:func:`sample_rate`, validated float in
+[0, 1], default 0 = off).
 """
 from __future__ import annotations
 
@@ -41,12 +40,9 @@ from typing import Callable, Dict, Iterator, List, Optional
 
 import jax
 
-__all__ = ["enabled", "enable", "disable", "range", "annotate", "set_timer",
-           "new_trace_id", "bind_trace", "current_traces", "current_trace",
-           "child_span", "sample_rate", "log_spans", "recent_spans",
-           "clear_span_log", "set_span_log_capacity"]
-
-_enabled = os.environ.get("RAFT_TPU_TRACE", "0") not in ("0", "", "false")
+__all__ = ["range", "annotate", "set_timer", "new_trace_id", "bind_trace",
+           "current_traces", "current_trace", "sample_rate", "log_spans",
+           "recent_spans", "clear_span_log", "set_span_log_capacity"]
 
 # (span_name, seconds) observer; None = timing off (the default)
 _timer: Optional[Callable[[str, float], None]] = None
@@ -60,37 +56,25 @@ def set_timer(fn: Optional[Callable[[str, float], None]]) -> None:
     _timer = fn
 
 
-def enabled() -> bool:
-    return _enabled
-
-
-def enable() -> None:
-    global _enabled
-    _enabled = True
-
-
-def disable() -> None:
-    global _enabled
-    _enabled = False
-
-
 @contextlib.contextmanager
-def range(name: str) -> Iterator[None]:  # noqa: A001 - mirrors nvtx::range
-    """Context-managed trace range (analog of ``raft::common::nvtx::range``)."""
-    timer = _timer
-    if timer is None and not _enabled:
-        yield
-        return
+def range(name: str,  # noqa: A001 - mirrors nvtx::range
+          out: Optional[Dict[str, float]] = None) -> Iterator[None]:
+    """Context-managed trace range (analog of ``raft::common::nvtx::range``).
+
+    Always a ``TraceAnnotation`` in the profile; its wall duration goes
+    to ``out[name]`` when ``out`` is given and to the installed timer."""
     t0 = time.perf_counter()
     try:
-        if _enabled:
-            with jax.profiler.TraceAnnotation(name):
-                yield
-        else:
+        with jax.profiler.TraceAnnotation(name):
             yield
     finally:
-        if timer is not None:
-            timer(name, time.perf_counter() - t0)
+        timer = _timer
+        if out is not None or timer is not None:
+            dt = time.perf_counter() - t0
+            if out is not None:
+                out[name] = dt
+            if timer is not None:
+                timer(name, dt)
 
 
 def annotate(name: str | None = None):
@@ -105,18 +89,8 @@ def annotate(name: str | None = None):
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            timer = _timer
-            if timer is None and not _enabled:
+            with range(label):
                 return fn(*args, **kwargs)
-            t0 = time.perf_counter()
-            try:
-                if _enabled:
-                    with jax.profiler.TraceAnnotation(label):
-                        return fn(*args, **kwargs)
-                return fn(*args, **kwargs)
-            finally:
-                if timer is not None:
-                    timer(label, time.perf_counter() - t0)
 
         wrapper.__raft_traced__ = True
         return wrapper
@@ -167,36 +141,6 @@ def current_trace() -> Optional[str]:
     """First bound trace ID, or None."""
     ids = getattr(_trace, "ids", ())
     return ids[0] if ids else None
-
-
-# -- child spans -----------------------------------------------------------
-@contextlib.contextmanager
-def child_span(name: str, out: Optional[Dict[str, float]] = None
-               ) -> Iterator[None]:
-    """Timed child span for one stage of a request.
-
-    Unlike :func:`range`, the duration is ALWAYS measured — callers gate
-    the call site themselves, opening child spans only on sampled work.
-    The duration lands in ``out[name]`` (when given), feeds the
-    installed span timer, and nests under the profiler range when
-    tracing is on. (The serving batcher times its five stages with its
-    own injectable clock for test determinism; this is the generic
-    building block for instrumenting any other pipeline the same way.)
-    """
-    t0 = time.perf_counter()
-    try:
-        if _enabled:
-            with jax.profiler.TraceAnnotation(name):
-                yield
-        else:
-            yield
-    finally:
-        dt = time.perf_counter() - t0
-        if out is not None:
-            out[name] = dt
-        timer = _timer
-        if timer is not None:
-            timer(name, dt)
 
 
 # -- sampling knob ---------------------------------------------------------

@@ -337,10 +337,11 @@ def _search_pallas(index, q, k, n_probes, offsets_j, sizes_j, precision,
     from ..ops.ivf_scan import _ivf_flat_scan_jit, coarse_probe, pad_for_scan
 
     mt = index.metric
-    probed = coarse_probe(q, index.centers, n_probes,
-                          metric=_PALLAS_METRICS[mt],
-                          center_norms=index.center_norms,
-                          precision=precision, survivors=survivors)
+    with tracing.range("raft_tpu::ivf_flat::coarse"):
+        probed = coarse_probe(q, index.centers, n_probes,
+                              metric=_PALLAS_METRICS[mt],
+                              center_norms=index.center_norms,
+                              precision=precision, survivors=survivors)
     lmax = int(index.list_sizes.max())
     # the aligned-DMA padding copies the dataset: cached once per index,
     # but NEVER stored from inside a trace (leaked tracers)
@@ -354,12 +355,13 @@ def _search_pallas(index, q, k, n_probes, offsets_j, sizes_j, precision,
             prepare_scan(index)
             cache = index._scan_pad
     interpret = jax.default_backend() != "tpu"
-    vals, rows = _ivf_flat_scan_jit(cache[1], cache[2], pen_p, cache[3],
-                                    probed, offsets_j, sizes_j, q, k, lmax,
-                                    _PALLAS_METRICS[mt], interpret,
-                                    precision)
-    ids = jnp.where(rows >= 0,
-                    jnp.take(index.source_ids, jnp.maximum(rows, 0)), -1)
+    with tracing.range("raft_tpu::ivf_flat::scan"):
+        vals, rows = _ivf_flat_scan_jit(cache[1], cache[2], pen_p, cache[3],
+                                        probed, offsets_j, sizes_j, q, k,
+                                        lmax, _PALLAS_METRICS[mt], interpret,
+                                        precision)
+        ids = jnp.where(rows >= 0,
+                        jnp.take(index.source_ids, jnp.maximum(rows, 0)), -1)
     if mt is DistanceType.L2SqrtExpanded:
         vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     elif mt is DistanceType.InnerProduct:

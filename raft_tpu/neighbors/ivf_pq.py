@@ -556,26 +556,28 @@ def _search_pallas(index: Index, q, k, n_probes, lut_dtype, precision,
             prepare_scan(index)
             cache = index._scan_cache
 
-    q_rot = hdot(q, index.rotation.T)
     coarse_metric = "ip" if mt is DistanceType.InnerProduct else "l2"
-    probed = coarse_probe(q_rot, index.centers_rot, n_probes,
-                          metric=coarse_metric, precision=precision,
-                          survivors=survivors)
-    sizes_j = jnp.asarray(index.list_sizes, jnp.int32)
-    if survivors is not None:
-        # zero-survivor lists scan as empty: sentinel rows only, no DMA
-        sizes_j = jnp.where(survivors > 0, sizes_j, 0)
+    with tracing.range("raft_tpu::ivf_pq::coarse"):
+        q_rot = hdot(q, index.rotation.T)
+        probed = coarse_probe(q_rot, index.centers_rot, n_probes,
+                              metric=coarse_metric, precision=precision,
+                              survivors=survivors)
     interpret = jax.default_backend() != "tpu"
-    vals, rows = _ivf_pq_scan_jit(
-        cache["codes_p"], cache["norms_p"], pen_p, index.centers_rot,
-        cache["cbm"], probed,
-        jnp.asarray(index.list_offsets[:-1], jnp.int32),
-        sizes_j, q_rot, k, lmax,
-        index.pq_dim, index.pq_book_size,
-        "ip" if mt is DistanceType.InnerProduct else "l2",
-        _lut_mode(lut_dtype), interpret, precision)
-    ids = jnp.where(rows >= 0,
-                    jnp.take(index.source_ids, jnp.maximum(rows, 0)), -1)
+    with tracing.range("raft_tpu::ivf_pq::scan"):
+        sizes_j = jnp.asarray(index.list_sizes, jnp.int32)
+        if survivors is not None:
+            # zero-survivor lists scan as empty: sentinel rows only, no DMA
+            sizes_j = jnp.where(survivors > 0, sizes_j, 0)
+        vals, rows = _ivf_pq_scan_jit(
+            cache["codes_p"], cache["norms_p"], pen_p, index.centers_rot,
+            cache["cbm"], probed,
+            jnp.asarray(index.list_offsets[:-1], jnp.int32),
+            sizes_j, q_rot, k, lmax,
+            index.pq_dim, index.pq_book_size,
+            "ip" if mt is DistanceType.InnerProduct else "l2",
+            _lut_mode(lut_dtype), interpret, precision)
+        ids = jnp.where(rows >= 0,
+                        jnp.take(index.source_ids, jnp.maximum(rows, 0)), -1)
     if mt is DistanceType.L2SqrtExpanded:
         vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     elif mt is DistanceType.InnerProduct:

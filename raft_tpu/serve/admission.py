@@ -60,8 +60,8 @@ class Request:
     pre-dispatch and between search chunks. Every request carries a
     ``trace_id`` (generated when not supplied) that stage decompositions
     and flight-recorder events are stamped with; ``dequeued_at`` is
-    stamped by the batcher worker when stage telemetry is enabled
-    (queue-wait measurement).
+    stamped by the batcher worker when it pops the request (queue-wait
+    measurement).
     """
 
     __slots__ = ("queries", "k", "deadline", "enqueued_at", "trace_id",

@@ -49,20 +49,24 @@ executable), so heavily mixed-k traffic trades fill ratio for
 k-padding — watch ``<name>.batch_fill`` and give hot k values their own
 bucket rather than widening an existing one.
 
-**Request-lifecycle telemetry** (docs/observability.md): every request
-carries a trace ID, and with ``trace_sample > 0`` (ctor arg or the
+**Request-lifecycle telemetry** (docs/observability.md): the worker's
+steps are trace ranges, one each per batch — ``raft_tpu::serve::pop``
+(waiting for requests), ``dispatch`` with ``pad`` inside, ``demux``
+with ``fetch`` and ``deliver`` inside — so an operator's profile names
+what the worker did in every device idle gap. Every request carries a
+trace ID, and with ``trace_sample > 0`` (ctor arg or the
 ``RAFT_TPU_TRACE_SAMPLE`` env knob) sampled batches record a five-stage
 latency decomposition per request — ``queue_wait`` (submit → worker
-pop), ``bucket_pad`` (coalesce + zero-pad), ``dispatch`` (host-side
-search-call wall), ``device`` (a ``block_until_ready`` probe — measured
-only on sampled batches, so steady-state dispatch stays asynchronous),
-``demux`` (device→host transfer + per-request slicing) — into
+pop), ``bucket_pad`` (the ``pad`` range), ``dispatch`` (the
+``dispatch`` range: late shed, pad and the search call's enqueue),
+``device`` (a ``block_until_ready`` probe — measured only on sampled
+batches, so steady-state dispatch stays asynchronous), ``demux`` (the
+``demux`` range: device→host transfer, slicing and delivery) — into
 ``<name>.stage.*_s`` histograms and the sampled span log
 (:func:`raft_tpu.core.tracing.recent_spans`). The worker binds the
 batch's trace IDs around dispatch, so demotions/faults/recompiles
 firing mid-batch land in the flight recorder stamped with the requests
-they hit. With sampling off the hot path pays one falsy check per
-probe site.
+they hit.
 """
 from __future__ import annotations
 
@@ -84,6 +88,14 @@ __all__ = ["BucketLadder", "MicroBatcher", "coalesce_block"]
 
 # the five per-request stages (docs/observability.md)
 STAGES = ("queue_wait", "bucket_pad", "dispatch", "device", "demux")
+
+# the worker's trace ranges (docs/observability.md)
+POP = "raft_tpu::serve::pop"
+DISPATCH = "raft_tpu::serve::dispatch"
+PAD = "raft_tpu::serve::pad"
+DEMUX = "raft_tpu::serve::demux"
+FETCH = "raft_tpu::serve::fetch"
+DELIVER = "raft_tpu::serve::deliver"
 
 
 def triage_partial(live: Sequence, offs: Sequence[int],
@@ -263,8 +275,6 @@ class MicroBatcher:
         self._batch_latency = r.histogram(f"{name}.batch_latency_s")
         self._fill = r.histogram(f"{name}.batch_fill",
                                  _metrics.RATIO_BUCKETS)
-        self._padding = r.histogram(f"{name}.padding_waste",
-                                    _metrics.RATIO_BUCKETS)
         self._thread: Optional[threading.Thread] = None
         if autostart:
             self.start()
@@ -341,9 +351,10 @@ class MicroBatcher:
                     pass           # must not stall the worker
             if pending is not None and len(self.queue) == 0:
                 pending = self._safe_demux(pending)
-            batch = self.queue.pop_batch(
-                self._max_batch, 0.0 if pending is not None else wait,
-                max_rows=self.ladder.max_queries)
+            with tracing.range(POP):
+                batch = self.queue.pop_batch(
+                    self._max_batch, 0.0 if pending is not None else wait,
+                    max_rows=self.ladder.max_queries)
             if not batch:
                 if pending is not None:
                     pending = self._safe_demux(pending)
@@ -354,10 +365,9 @@ class MicroBatcher:
             # operator knob: simulate a stalled worker/device
             # (RAFT_TPU_FAULTS='slow_dispatch@<name>.batch=0.1')
             faults.sleep_if(f"{self._name}.batch")
-            if self._stages is not None:
-                now = self._clock()
-                for r in batch:
-                    r.dequeued_at = now
+            now = self._clock()
+            for r in batch:
+                r.dequeued_at = now
             groups: dict = {}
             for r in batch:
                 groups.setdefault(self.ladder.bucket_k(r.k), []).append(r)
@@ -427,43 +437,46 @@ class MicroBatcher:
         Returns the pending-demux state, or None when nothing was
         dispatched (all shed, or a deadline expired mid-dispatch and
         partials were delivered)."""
-        # late shed: a deadline can expire between admission pop and here
-        # (e.g. an earlier group's dispatch, or an armed slow worker)
-        live = []
-        for r in reqs:
-            if r.deadline is not None and r.deadline.expired():
-                self.queue.shed(r)
-            else:
-                live.append(r)
-        if not live:
+        spans: dict = {}     # the batch's range durations (tracing.range)
+        expired = None
+        with tracing.range(DISPATCH, out=spans):
+            # late shed: a deadline can expire between admission pop and
+            # here (e.g. an earlier group's dispatch, or an armed slow
+            # worker)
+            live = []
+            for r in reqs:
+                if r.deadline is not None and r.deadline.expired():
+                    self.queue.shed(r)
+                else:
+                    live.append(r)
+            if not live:
+                return None
+            # stage-telemetry probe decision: one falsy check when
+            # disabled; when enabled, every _probe_every-th group tells
+            # the full story
+            probe = False
+            if self._stages is not None:
+                self._probe_tick += 1
+                probe = (self._probe_tick - 1) % self._probe_every == 0
+            rows = sum(r.rows for r in live)
+            mb = self.ladder.bucket_queries(rows)
+            with tracing.range(PAD, out=spans):
+                block, offs = coalesce_block(live, mb, self._dim)
+            try:
+                # bind the batch's trace IDs + label the compile context:
+                # a demotion, fault or recompile firing inside the search
+                # is stamped with the requests (and shape bucket) it hit
+                with tracing.bind_trace(*(r.trace_id for r in live)), \
+                        _warmup.compile_context(f"{self._name}:{mb}x{kb}"):
+                    out = self._search(block, kb,
+                                       res=self._tightest_deadline(live))
+            except DeadlineExceeded as e:
+                expired = e
+        if expired is not None:
+            self._deliver_partial(kb, live, offs, expired)
             return None
-        # stage-telemetry probe decision: one falsy check when disabled;
-        # when enabled, every _probe_every-th group tells the full story
-        probe = False
-        if self._stages is not None:
-            self._probe_tick += 1
-            probe = (self._probe_tick - 1) % self._probe_every == 0
-        rows = sum(r.rows for r in live)
-        mb = self.ladder.bucket_queries(rows)
-        t_pad = self._clock() if probe else 0.0
-        block, offs = coalesce_block(live, mb, self._dim)
-        pad_dt = self._clock() - t_pad if probe else 0.0
-        t0 = self._clock()
-        try:
-            # bind the batch's trace IDs + label the compile context:
-            # a demotion, fault or recompile firing inside the search is
-            # stamped with the requests (and shape bucket) it hit
-            with tracing.bind_trace(*(r.trace_id for r in live)), \
-                    _warmup.compile_context(f"{self._name}:{mb}x{kb}"):
-                out = self._search(block, kb,
-                                   res=self._tightest_deadline(live))
-        except DeadlineExceeded as e:
-            self._deliver_partial(kb, live, offs, e)
-            return None
-        dt = self._clock() - t0
         return {"kb": kb, "live": live, "offs": offs, "out": out,
-                "probe": probe, "pad_dt": pad_dt, "dt": dt, "mb": mb,
-                "rows": rows}
+                "probe": probe, "spans": spans, "mb": mb, "rows": rows}
 
     def _demux_phase(self, pend) -> None:
         """Block on the dispatched group's results, slice them back to
@@ -471,9 +484,8 @@ class MicroBatcher:
         the next group's dispatch is in flight (the double buffer)."""
         kb, live, offs, out = (pend["kb"], pend["live"], pend["offs"],
                                pend["out"])
-        probe, pad_dt, dt, mb, rows = (pend["probe"], pend["pad_dt"],
-                                       pend["dt"], pend["mb"],
-                                       pend["rows"])
+        probe, spans, mb, rows = (pend["probe"], pend["spans"], pend["mb"],
+                                  pend["rows"])
         device_dt = 0.0
         if probe:
             # the off-hot-path device probe: dispatch is asynchronous, so
@@ -487,9 +499,40 @@ class MicroBatcher:
             d, i, shards_ok = out
         else:
             d, i = out
-        t_dmx = self._clock() if probe else 0.0
-        d = np.asarray(d)
-        i = np.asarray(i)
+        with tracing.range(DEMUX, out=spans):
+            with tracing.range(FETCH):
+                d = np.asarray(d)
+                i = np.asarray(i)
+            with tracing.range(DELIVER):
+                self._deliver(live, offs, d, i, shards_ok)
+                self._served.inc(len(live))
+                self._batches.inc()
+                self._reg.counter(f"{self._name}.dispatch.{mb}x{kb}").inc()
+                self._batch_latency.observe(spans[DISPATCH])
+                self._fill.observe(rows / mb)
+        if probe:
+            # AFTER delivery, and guarded: a failing observer (a
+            # user-supplied registry) must not fail a batch whose
+            # results were already computed, nor delay them behind
+            # 5 histogram writes per co-batched request
+            try:
+                tel = self._stages
+                bucket = f"{mb}x{kb}"
+                for r in live:
+                    stages = {"queue_wait": max(0.0, r.dequeued_at
+                                                - r.enqueued_at),
+                              "bucket_pad": spans[PAD],
+                              "dispatch": spans[DISPATCH],
+                              "device": device_dt, "demux": spans[DEMUX]}
+                    for s, v in stages.items():
+                        tel[s].observe(v)
+                    tracing.log_spans(r.trace_id, stages, rows=r.rows,
+                                      k=r.k, bucket=bucket)
+            except Exception:  # noqa: BLE001 - telemetry must not
+                pass           # break serving
+
+    def _deliver(self, live, offs, d, i, shards_ok) -> None:
+        """Slice the fetched block back to its requests and deliver."""
         if shards_ok is not None:
             ok = np.asarray(shards_ok, bool)
             self._healthy.set(int(ok.sum()))
@@ -498,7 +541,6 @@ class MicroBatcher:
         results = [SearchResult(d[o:o + r.rows, :r.k],
                                 i[o:o + r.rows, :r.k], shards_ok)
                    for r, o in zip(live, offs)]
-        demux_dt = self._clock() - t_dmx if probe else 0.0
         now = self._clock()
         for r, res_r in zip(live, results):
             r.set_result(res_r)
@@ -515,31 +557,6 @@ class MicroBatcher:
                         trace_id=r.trace_id)
             except Exception:  # noqa: BLE001 - telemetry must not break
                 pass           # serving
-        if probe:
-            # AFTER delivery, and guarded: a failing observer (a
-            # user-supplied registry) must not fail a batch whose
-            # results were already computed, nor delay them behind
-            # 5 histogram writes per co-batched request
-            try:
-                tel = self._stages
-                bucket = f"{mb}x{kb}"
-                for r in live:
-                    stages = {"queue_wait": max(0.0, r.dequeued_at
-                                                - r.enqueued_at),
-                              "bucket_pad": pad_dt, "dispatch": dt,
-                              "device": device_dt, "demux": demux_dt}
-                    for s, v in stages.items():
-                        tel[s].observe(v)
-                    tracing.log_spans(r.trace_id, stages, rows=r.rows,
-                                      k=r.k, bucket=bucket)
-            except Exception:  # noqa: BLE001 - telemetry must not
-                pass           # break serving
-        self._served.inc(len(live))
-        self._batches.inc()
-        self._reg.counter(f"{self._name}.dispatch.{mb}x{kb}").inc()
-        self._batch_latency.observe(dt)
-        self._fill.observe(rows / mb)
-        self._padding.observe((mb - rows) / mb)
 
     def _deliver_partial(self, kb: int, live: List[Request],
                          offs: List[int], e: DeadlineExceeded) -> None:

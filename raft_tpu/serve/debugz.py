@@ -202,7 +202,7 @@ def snapshot(batcher=None, registry=None, events_n: int = 50,
 
 def _fmt_hist(name: str, h: dict) -> str:
     # unit by naming convention: only *_s histograms are seconds —
-    # ratio histograms (batch_fill, padding_waste) render unitless
+    # ratio histograms (batch_fill) render unitless
     u = "s" if name.endswith("_s") else ""
     return (f"  {name}: n={h['count']} p50={h['p50']:.4g}{u} "
             f"p90={h['p90']:.4g}{u} p99={h['p99']:.4g}{u} max={h['max']:.4g}{u}")

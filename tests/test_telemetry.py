@@ -6,8 +6,10 @@ guarantee (docs/observability.md).
 Everything except the recompile-watch test runs on STUB searchers (no
 XLA compiles) so the whole file stays well under the tier-1 budget.
 """
+import glob
 import io
 import json
+import os
 import time
 
 import jax
@@ -17,7 +19,7 @@ import pytest
 from raft_tpu.core import events, faults, serialize, tracing
 from raft_tpu.core.deadline import Deadline, DeadlineExceeded
 from raft_tpu.core.errors import CorruptIndexError
-from raft_tpu.serve import debugz, metrics
+from raft_tpu.serve import batcher as batcher_mod, debugz, metrics
 from raft_tpu.serve.batcher import STAGES, BucketLadder, MicroBatcher
 
 pytestmark = pytest.mark.serve
@@ -57,9 +59,11 @@ class TestTracingPrimitives:
 
     def test_child_span_collects(self):
         out = {}
-        with tracing.child_span("unit::stage", out):
-            pass
-        assert out["unit::stage"] >= 0.0
+        with tracing.range("unit::outer", out=out):
+            with tracing.range("unit::stage", out=out):
+                time.sleep(0.001)
+        assert out["unit::stage"] >= 0.001
+        assert out["unit::outer"] >= out["unit::stage"]
 
     def test_sample_rate_validation(self, monkeypatch):
         monkeypatch.delenv("RAFT_TPU_TRACE_SAMPLE", raising=False)
@@ -144,11 +148,30 @@ class TestTracePropagation:
         # shared batch stages agree exactly
         assert s1["stages"]["queue_wait"] > s2["stages"]["queue_wait"]
         assert s1["stages"]["dispatch"] == s2["stages"]["dispatch"]
+        # pad lies inside the dispatch range; the dispatch histogram
+        # observes the same range
+        assert s1["stages"]["bucket_pad"] <= s1["stages"]["dispatch"]
         assert s1["rows"] == 3 and s2["rows"] == 2
         # metrics snapshot carries the five-stage latency decomposition
         snap = reg.snapshot()["histograms"]
         for s in STAGES:
             assert snap[f"serve.stage.{s}_s"]["count"] == 2
+        assert snap["serve.batch_latency_s"]["sum"] == pytest.approx(
+            s1["stages"]["dispatch"])
+
+    def test_queue_wait_stamped_without_sampling(self, reg):
+        """Every popped request gets its dequeue stamp, whatever the
+        sampling rate: a caller's queue-wait reading does not depend on
+        it."""
+        b = MicroBatcher(stub_search, DIM, ladder=BucketLadder((8,), (8,)),
+                         registry=reg, autostart=False, trace_sample=0,
+                         max_wait_s=0.0)
+        r = b.submit(np.zeros((1, DIM), np.float32), 5)
+        time.sleep(0.002)
+        b.start()
+        r.result(60)
+        b.close()
+        assert r.dequeued_at - r.enqueued_at >= 0.002
 
     def test_sampling_interval(self, reg):
         """trace_sample=0.5 decomposes every 2nd batch (deterministic
@@ -431,6 +454,99 @@ class TestDriftGuard:
                 "qcache_stale"} <= events.WELL_KNOWN_KINDS
 
 
+def _profile_spans(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; the ``raft_tpu::``
+    spans of each host thread line, as ``[[(name, start_ns, end_ns)]]``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)
+    assert len(path) == 1
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path[0]).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            spans = [(e.name, e.start_ns, e.end_ns) for e in line.events
+                     if e.name.startswith("raft_tpu::")]
+            if spans:
+                lines.append(spans)
+    return lines
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+class TestProfileSpans:
+    """The library's trace ranges reach an operator's profile with no
+    switch set: the batcher's steps on its worker's line, and a search's
+    steps inside its entry point's span."""
+
+    def test_batcher_steps_nest_on_the_worker_line(self, reg, tmp_path):
+        b = MicroBatcher(stub_search, DIM, ladder=BucketLadder((8,), (8,)),
+                         registry=reg, autostart=False, max_wait_s=0.001)
+        req = b.submit(np.zeros((2, DIM), np.float32), 5)
+
+        def serve_one():
+            b.start()
+            req.result(60)
+            b.close()
+
+        lines = _profile_spans(tmp_path, serve_one)
+        serve = [ln for ln in lines
+                 if any(n.startswith("raft_tpu::serve::") for n, *_ in ln)]
+        assert len(serve) == 1, "the worker's steps span several lines"
+        by = {}
+        for ev in serve[0]:
+            by.setdefault(ev[0], []).append(ev)
+        names = (batcher_mod.POP, batcher_mod.DISPATCH, batcher_mod.PAD,
+                 batcher_mod.DEMUX, batcher_mod.FETCH, batcher_mod.DELIVER)
+        assert set(by) == set(names)
+        # one dispatch and one demux for the one batch; pop before both
+        for n in names[1:]:
+            assert len(by[n]) == 1, n
+        (disp,), (dmx,) = by[batcher_mod.DISPATCH], by[batcher_mod.DEMUX]
+        assert _inside(by[batcher_mod.PAD][0], disp)
+        assert _inside(by[batcher_mod.FETCH][0], dmx)
+        assert _inside(by[batcher_mod.DELIVER][0], dmx)
+        assert by[batcher_mod.FETCH][0][2] <= by[batcher_mod.DELIVER][0][1]
+        assert disp[2] <= dmx[1]
+        assert by[batcher_mod.POP][0][2] <= disp[1]
+
+    def test_ivf_flat_search_steps_inside_the_search_span(self, tmp_path):
+        from raft_tpu.neighbors import ivf_flat
+
+        rng = np.random.default_rng(0)
+        data = rng.standard_normal((256, DIM)).astype(np.float32)
+        index = ivf_flat.build(data, ivf_flat.IndexParams(n_lists=4,
+                                                          seed=0))
+        sp = ivf_flat.SearchParams(n_probes=2)
+        q = data[:8]
+
+        def search():
+            jax.block_until_ready(
+                ivf_flat.search(index, q, 4, sp, algo="pallas"))
+
+        search()                      # compile outside the profile
+        (line,) = _profile_spans(tmp_path, search)
+        ev = {n: (n, s, e) for n, s, e in line}
+        assert len(ev) == len(line)           # one span of each name
+        outer = ev.pop("raft_tpu::ivf_flat::search")
+        coarse = ev["raft_tpu::ivf_flat::coarse"]
+        scan = ev["raft_tpu::ivf_flat::scan"]
+        assert coarse[2] <= scan[1]
+        # every step, the eager select_k of the probe among them, lies
+        # inside the entry point's span
+        for inner in ev.values():
+            assert _inside(inner, outer), inner
+
+
 class TestZeroOverheadWhenOff:
     def test_disabled_path_runs_no_device_probe(self, reg, monkeypatch):
         """With sampling off, the serving hot path must never sync the
@@ -461,28 +577,23 @@ class TestZeroOverheadWhenOff:
         of magnitude). Generous bound: timing on the 1-core CI box is
         noisy."""
         tracing.set_timer(None)
-        was_enabled = tracing.enabled()
-        tracing.disable()
-        try:
-            def raw(x):
-                return x + 1
 
-            wrapped = tracing.annotate("unit::overhead")(raw)
+        def raw(x):
+            return x + 1
 
-            def bench(fn, n=20000):
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    for i in range(n):
-                        fn(i)
-                    best = min(best, (time.perf_counter() - t0) / n)
-                return best
+        wrapped = tracing.annotate("unit::overhead")(raw)
 
-            base = bench(raw)
-            cost = bench(wrapped)
-            assert cost - base < 20e-6, (
-                f"disabled annotate overhead {cost - base:.2e}s/call — "
-                "a probe is running on the disabled path")
-        finally:
-            if was_enabled:
-                tracing.enable()
+        def bench(fn, n=20000):
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                for i in range(n):
+                    fn(i)
+                best = min(best, (time.perf_counter() - t0) / n)
+            return best
+
+        base = bench(raw)
+        cost = bench(wrapped)
+        assert cost - base < 20e-6, (
+            f"annotate overhead {cost - base:.2e}s/call with no profiler "
+            "collecting — a probe is running on the idle path")
