@@ -12,11 +12,15 @@ The last term is one GEMM against a block-diagonal codebook matrix (the
 per-query LUT), and the per-row sum over coded entries is a one-hot
 GEMM — FLOP-rich but exactly the dense shape the MXU wants, while the
 dataset stays PQ-compressed in HBM (the point of PQ: DEEP-1B-class
-corpora that raw f32 cannot hold). Row norms ||c + dec||² precompute at
-build like brute-force norms. The one-hot/LUT GEMM runs in bf16 when the
-caller asks for the reference's fp16-LUT mode (lut_dtype), f32 when exact,
-or int8 (the fp8-LUT role: per-subspace symmetric codebook quantization,
-double-rate MXU int8 decode with exact int32 accumulation).
+corpora that raw f32 cannot hold). Per row chunk the kernel transposes
+the code tile, compares each subspace's code row against a sublane iota
+of the book to get the one-hot OHᵀ, decodes decᵀ = CB @ OHᵀ and scores
+q @ decᵀ: two GEMMs a chunk, no gather. Row norms ||c + dec||²
+precompute at build like brute-force norms. The decode GEMM runs in bf16
+when the caller asks for the reference's fp16-LUT mode (lut_dtype), f32
+when exact, or int8 (the fp8-LUT role: per-subspace symmetric codebook
+quantization, double-rate MXU int8 decode with exact int32
+accumulation).
 """
 from __future__ import annotations
 
@@ -38,12 +42,13 @@ __all__ = ["ivf_pq_scan", "make_cb_matrix", "decoded_row_norms"]
 def make_cb_matrix(codebooks: jax.Array) -> jax.Array:
     """(pq_dim, book, pq_len) PER_SUBSPACE codebooks → block-structured
     (rot_dim_pad, pq_dim*book) matrix CB with SUBSPACE-MAJOR columns,
-    CB[s*pq_len + l, s*book + b] = cb[s, b, l], so a row's one-hot code
-    vector (column s*book + b set when its subspace-s code is b) times
-    CBᵀ is the row's decoded vector — no sub-lane reshapes or gathers
-    in-kernel. The kernel builds that one-hot with a small exact GEMM
-    (see ``_kernel_body``), not ``pltpu.repeat``, whose interpret and
-    Mosaic semantics have diverged across jax versions."""
+    CB[s*pq_len + l, s*book + b] = cb[s, b, l], so CB times a row's
+    one-hot code vector (entry s*book + b set when its subspace-s code is
+    b) is the row's decoded vector — no sub-lane reshapes or gathers
+    in-kernel. The kernel builds a row chunk's one-hot transposed,
+    (pq_dim*book, rows), by one broadcast compare of the transposed code
+    tile, so CB @ OHᵀ decodes the whole chunk in one GEMM (see
+    ``_kernel_body``)."""
     pq_dim, book, pq_len = codebooks.shape
     rot_dim = pq_dim * pq_len
     rot_pad = round_up_to(rot_dim, 128)
@@ -145,9 +150,9 @@ def _kernel_body(off, size, qb_ref, qn_ref, dn_ref, pen_ref,
     copy.start()
     q = qb_ref[0]                                    # (QG, rot_pad)
     pqb = pq_dim * book
-    code_pad = codes_vmem.shape[1]
     lut_t = cb_ref.dtype        # bf16 = fp16-LUT mode; int8 = fp8-LUT role
     int8_mode = lut_t == jnp.int8
+    acc_t = jnp.int32 if int8_mode else jnp.float32
     qc = jax.lax.dot_general(
         q, cent_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -155,48 +160,41 @@ def _kernel_body(off, size, qb_ref, qn_ref, dn_ref, pen_ref,
     copy.wait()
 
     # Associativity saves VMEM: q @ (CB @ OHᵀ) instead of (q @ CB) @ OHᵀ.
-    # CB @ OHᵀ is exactly the chunk's *decoded rows* (rot_pad, cw) — a few
-    # hundred KB — whereas the per-query LUT (QG, pqb) is megabytes at
-    # large pq_dim. One-hot chunks are sized to ~4 MB; at very large lmax
-    # this unrolls more GEMM pairs (compile-time cost), the accepted
-    # tradeoff for a bounded VMEM footprint.
+    # CB @ OHᵀ is exactly the chunk's *decoded rows*, transposed
+    # (rot_pad, cw) — a few hundred KB — whereas the per-query LUT
+    # (QG, pqb) is megabytes at large pq_dim. One-hot chunks are sized to
+    # ~4 MB; at very large lmax this unrolls more GEMM pairs (compile-time
+    # cost), the accepted tradeoff for a bounded VMEM footprint.
     #
     # int8 mode (role of the reference's fp8 smem LUT,
     # ivf_pq_types.hpp:110-146): CB arrives pre-quantized with
     # per-subspace symmetric scales; the one-hot is int8 too, so the
     # decode GEMM runs on the MXU's double-rate int8 path and accumulates
-    # exactly in int32. The per-ROW scale vector (subspaces are disjoint
+    # exactly in int32. The per-ROW scale column (subspaces are disjoint
     # row/column blocks of CB) rescales the decoded chunk before scoring.
     itemsize = lut_t.itemsize
     chunk = max(128, min(lmax, ((4 << 20) // (pqb * itemsize)) // 128 * 128))
     scale = -2.0 if metric == "l2" else -1.0
+    # OHᵀ[s*book + b, r] = (codes[r, s] == b): each subspace's code row,
+    # broadcast down the sublanes, against b = 0..book-1 (no lane-dim
+    # reshape). One iota a chunk width, built once a grid step: Mosaic
+    # cannot slice a ragged last chunk's out of the full one.
+    b_iota = {}
     terms = []
     for c0 in range(0, lmax, chunk):
         cw = min(chunk, lmax - c0)
-        # codes_rep[r, s*book + b] = codes[r, s]: one exact GEMM against
-        # the 0/1 expansion E[s, col] = (col // book == s) (byte codes
-        # are exact in bf16; each output sums one nonzero product)
-        codes_c = codes_vmem[c0 : c0 + cw, :].astype(jnp.int32).astype(
-            jnp.float32).astype(jnp.bfloat16)            # (cw, code_pad)
-        e_row = jax.lax.broadcasted_iota(jnp.int32, (code_pad, pqb), 0)
-        e_col = jax.lax.broadcasted_iota(jnp.int32, (code_pad, pqb), 1)
-        expand = (e_row == e_col // book).astype(jnp.bfloat16)
-        codes_rep = jax.lax.dot_general(
-            codes_c, expand, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (cw, pqb)
-        j = jax.lax.broadcasted_iota(jnp.int32, (cw, pqb), 1)
-        oh = (codes_rep == (j % book).astype(jnp.float32)).astype(lut_t)
+        ct = codes_vmem[c0 : c0 + cw, :].astype(jnp.int32).T[:pq_dim]
+        if cw not in b_iota:
+            b_iota[cw] = jax.lax.broadcasted_iota(jnp.int32, (1, book, cw), 1)
+        oh = (ct[:, None, :] == b_iota[cw]).astype(lut_t).reshape(
+            pqb, cw)                                 # (pqb, cw) OHᵀ
+        decoded = jax.lax.dot_general(
+            cb_ref[:], oh, (((1,), (0,)), ((), ())),
+            preferred_element_type=acc_t)            # (rot_pad, cw)
         if int8_mode:
-            dec_i = jax.lax.dot_general(
-                oh, cb_ref[:], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32)    # (cw, rot_pad)
-            decoded = dec_i.astype(jnp.float32) * scl_ref[:]
-        else:
-            decoded = jax.lax.dot_general(
-                oh, cb_ref[:], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (cw, rot_pad)
+            decoded = decoded.astype(jnp.float32) * scl_ref[:]
         terms.append(scale * jax.lax.dot_general(
-            q, decoded, (((1,), (1,)), ((), ())),
+            q, decoded, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision(precision))) # (QG, cw)
     pq_term = jnp.concatenate(terms, axis=1) if len(terms) > 1 else terms[0]
@@ -242,7 +240,7 @@ def _kernel_body(off, size, qb_ref, qn_ref, dn_ref, pen_ref,
     static_argnames=("k", "lmax", "n_groups", "pq_dim", "book", "metric",
                      "interpret", "precision", "has_pen"))
 def _scan_groups(qblocks, qnorms, dn_slices, pen_slices, gcenters, cb_matrix,
-                 scale_row, codes, goffs, gsizes, k, lmax, n_groups, pq_dim,
+                 scale_col, codes, goffs, gsizes, k, lmax, n_groups, pq_dim,
                  book, metric, interpret, precision, has_pen):
     kp = round_up_to(k, 128)
     rot_pad = qblocks.shape[2]
@@ -288,7 +286,7 @@ def _scan_groups(qblocks, qnorms, dn_slices, pen_slices, gcenters, cb_matrix,
         ],
         interpret=interpret,
     )(goffs, gsizes, qblocks, qnorms, dn_slices, pen_slices, gcenters,
-      cb_matrix, scale_row, codes)
+      cb_matrix, scale_col, codes)
 
 
 def ivf_pq_scan(
@@ -348,7 +346,7 @@ def _ivf_pq_scan_jit(codes_p, norms_p, pen_p, centers_rot, cb_matrix, probed,
     rot_dim = q_rot.shape[1]
     rot_pad = cb_matrix.shape[0]
     lmax_pad = scan_window(lmax)
-    scale_row = jnp.ones((1, rot_pad), jnp.float32)
+    scale_col = jnp.ones((rot_pad, 1), jnp.float32)
     if lut_mode == "int8":
         # fp8-LUT role (ivf_pq_types.hpp:110-146): per-subspace symmetric
         # quantization of the block-diagonal CB. Column s*book+b and row
@@ -363,9 +361,9 @@ def _ivf_pq_scan_jit(codes_p, norms_p, pen_p, centers_rot, cb_matrix, probed,
             jnp.round(cb_matrix.reshape(rot_pad, pq_dim, book)
                       / scales[None, :, None]), -127, 127
         ).astype(jnp.int8).reshape(rot_pad, pq_dim * book)
-        scale_row = jnp.pad(jnp.repeat(scales, pq_len),
+        scale_col = jnp.pad(jnp.repeat(scales, pq_len),
                             (0, rot_pad - rot_dim),
-                            constant_values=1.0)[None, :]
+                            constant_values=1.0)[:, None]
     elif lut_mode == "bf16":
         # fp16-LUT mode: cast here so the kernel's operand dtypes match
         cb_matrix = cb_matrix.astype(jnp.bfloat16)
@@ -391,7 +389,7 @@ def _ivf_pq_scan_jit(codes_p, norms_p, pen_p, centers_rot, cb_matrix, probed,
             pen_p, (o,), (lmax_pad,)))(goffs_al)[:, None, :]
 
     gv, gi = _scan_groups(qblocks, qn, dn, pen, gcenters, cb_matrix,
-                          scale_row, codes_p, goffs, gsizes, k, lmax_pad,
+                          scale_col, codes_p, goffs, gsizes, k, lmax_pad,
                           int(n_groups), pq_dim, book, metric, interpret,
                           precision, pen_p is not None)
     return merge_pairs(gv, gi, flat, order, m, p, k)

@@ -312,3 +312,67 @@ def test_pq_build_from_batches(dataset, queries):
     _, i = ivf_pq.search(idx, queries, 10, ivf_pq.SearchParams(32))
     _, want = naive_knn(dataset, queries, 10)
     assert calc_recall(np.asarray(i), want) >= 0.45
+
+
+def _mode_codebooks(cb, lut_mode):
+    """The codebooks as ``lut_mode`` rounds them, in float64: bf16 casts
+    them; int8 quantizes each subspace to symmetric int8 with its own
+    scale, in float32 as ``ivf_pq_scan`` does."""
+    if lut_mode == "bf16":
+        return np.asarray(jnp.asarray(cb).astype(jnp.bfloat16), np.float64)
+    if lut_mode == "int8":
+        scale = np.maximum(np.abs(cb).max(axis=(1, 2)),
+                           np.float32(1e-12)) / np.float32(127.0)
+        q8 = np.clip(np.round(cb / scale[:, None, None]), -127, 127)
+        return q8 * scale[:, None, None].astype(np.float64)
+    return cb.astype(np.float64)
+
+
+@pytest.mark.parametrize("pq_bits", [4, 8])
+@pytest.mark.parametrize("lut_mode", ["f32", "bf16", "int8"])
+def test_pq_scan_decode_exact(lut_mode, pq_bits):
+    """The Pallas PQ scan's approximate distances are ||q - (c_l + dec)||²
+    of the codebook as the LUT mode rounds it, and its ids are the top-k
+    over the probed lists. The lists differ in size and start off the
+    8-row DMA alignment; at pq_bits=8 the one-hot spans several row chunks
+    (a ragged last one in bf16), so a slip in the code transpose or a
+    subspace offset moves distances far past the tolerance."""
+    from raft_tpu.ops.ivf_pq_scan import ivf_pq_scan, make_cb_matrix
+
+    rng = np.random.default_rng(11 + pq_bits)
+    pq_dim, pq_len, book, k = 32, 4, 1 << pq_bits, 10
+    rot_dim = pq_dim * pq_len
+    sizes = np.array([300, 37, 5, 261, 129, 83])
+    offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+    n, n_lists, m = int(sizes.sum()), len(sizes), 12
+    codes = rng.integers(0, book, (n, pq_dim), dtype=np.uint8)
+    cb = rng.standard_normal((pq_dim, book, pq_len)).astype(np.float32)
+    centers = rng.standard_normal((n_lists, rot_dim)).astype(np.float32)
+    q = rng.standard_normal((m, rot_dim)).astype(np.float32)
+    probed = np.stack([rng.choice(n_lists, 3, replace=False)
+                       for _ in range(m)]).astype(np.int32)
+
+    cbm = _mode_codebooks(cb, lut_mode)
+    dec = cbm[np.arange(pq_dim), codes].reshape(n, rot_dim)
+    x = centers[np.repeat(np.arange(n_lists), sizes)] + dec
+    ref = ((q[:, None, :].astype(np.float64) - x[None]) ** 2).sum(-1)
+    norms = (x * x).sum(1).astype(np.float32)
+
+    vals, rows = ivf_pq_scan(
+        jnp.asarray(codes), jnp.asarray(norms), jnp.asarray(centers),
+        make_cb_matrix(jnp.asarray(cb)), jnp.asarray(probed),
+        jnp.asarray(offsets, jnp.int32), jnp.asarray(sizes, jnp.int32),
+        jnp.asarray(q), k, int(sizes.max()), pq_dim, book, lut_mode=lut_mode)
+    vals, rows = np.asarray(vals), np.asarray(rows)
+
+    for i in range(m):
+        live = np.concatenate([np.arange(offsets[l], offsets[l] + sizes[l])
+                               for l in probed[i]])
+        want = live[np.argsort(ref[i, live], kind="stable")]
+        assert len(set(rows[i])) == k and set(rows[i]) <= set(live)
+        np.testing.assert_allclose(vals[i], ref[i, rows[i]], rtol=1e-4)
+        kth, nxt = ref[i, want[k - 1]], ref[i, want[k]]
+        if nxt - kth > 1e-4 * kth:
+            assert set(rows[i]) == set(want[:k])
+        else:                              # a tie at the k-th place
+            assert (ref[i, rows[i]] <= kth * (1 + 1e-4)).all()

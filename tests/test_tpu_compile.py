@@ -99,21 +99,33 @@ def test_ivf_flat_scan(one_chip, dtype):
              ((_M, D), jnp.float32))
 
 
-@pytest.mark.parametrize("lut", ["f32", "bf16", "int8"])
-def test_ivf_pq_scan(one_chip, lut):
+def _compile_ivf_pq_scan(sharding, lut, pq_bits, m):
     from raft_tpu.ops.ivf_pq_scan import ivf_pq_scan, make_cb_matrix
 
-    pq_dim, book = 64, 256
+    pq_dim, book = 64, 1 << pq_bits
 
     def fn(codes, norms, centers, cbs, probed, offs, sizes, q):
         return ivf_pq_scan(codes, norms, centers, make_cb_matrix(cbs), probed,
                            offs, sizes, q, k=K * 2, lmax=_LMAX, pq_dim=pq_dim,
                            book=book, lut_mode=lut, interpret=False)
 
-    _compile(one_chip, fn, ((N, pq_dim), jnp.uint8), ((N,), jnp.float32),
+    _compile(sharding, fn, ((N, pq_dim), jnp.uint8), ((N,), jnp.float32),
              ((_L, D), jnp.float32), ((pq_dim, book, D // pq_dim), jnp.float32),
-             ((_M, _P), jnp.int32), ((_L,), jnp.int32), ((_L,), jnp.int32),
-             ((_M, D), jnp.float32))
+             ((m, _P), jnp.int32), ((_L,), jnp.int32), ((_L,), jnp.int32),
+             ((m, D), jnp.float32))
+
+
+@pytest.mark.parametrize("lut", ["f32", "bf16", "int8"])
+def test_ivf_pq_scan(one_chip, lut):
+    _compile_ivf_pq_scan(one_chip, lut, 8, _M)
+
+
+# the served bucket's 256-query grid, and the one-hot's (pq_dim, book)
+# row merge at a 16-entry book (the kernel body does not depend on m)
+@pytest.mark.parametrize("pq_bits,m", [(8, 256), (4, 256)])
+@pytest.mark.parametrize("lut", ["f32", "bf16", "int8"])
+def test_ivf_pq_scan_bits_batch(one_chip, lut, pq_bits, m):
+    _compile_ivf_pq_scan(one_chip, lut, pq_bits, m)
 
 
 _DEG, _ITOPK, _PQ_DIM = 64, 64, 16
