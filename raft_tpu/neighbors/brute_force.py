@@ -493,15 +493,16 @@ def prepare_fused(index: Index) -> None:
     cache = getattr(index, "_fused_pad", None)
     if cache is not None and cache[0] == key:
         return
-    d_pad = jnp.pad(d, ((0, n_pad - n), (0, dim_p - dim)))
-    base_pen = jnp.pad(jnp.zeros((n,), jnp.float32), (0, n_pad - n),
-                       constant_values=jnp.inf)
-    norms_pad = (None if index.norms is None
-                 else jnp.pad(jnp.asarray(index.norms, jnp.float32),
-                              (0, n_pad - n)))
-    scales_pad = (None if index.scales is None
-                  else jnp.pad(jnp.asarray(index.scales, jnp.float32),
-                               (0, n_pad - n)))
+    with tracing.range("raft_tpu::brute_force::prepare"):
+        d_pad = jnp.pad(d, ((0, n_pad - n), (0, dim_p - dim)))
+        base_pen = jnp.pad(jnp.zeros((n,), jnp.float32), (0, n_pad - n),
+                           constant_values=jnp.inf)
+        norms_pad = (None if index.norms is None
+                     else jnp.pad(jnp.asarray(index.norms, jnp.float32),
+                                  (0, n_pad - n)))
+        scales_pad = (None if index.scales is None
+                      else jnp.pad(jnp.asarray(index.scales, jnp.float32),
+                                   (0, n_pad - n)))
     index._fused_pad = (key, d_pad, norms_pad, base_pen, scales_pad)
 
 
@@ -588,16 +589,29 @@ def _search_pallas(index: Index, q, k, filter, valid_rows, precision):
                          precision=precision, scales=sc,
                          int4_dim=index.logical_dim)
 
-    if m > chunk > 0:
-        vals, idxs = _chunked_queries(one, q, chunk, k)
-    else:
-        vals, idxs = one(q)
+    with tracing.range("raft_tpu::brute_force::fused"):
+        if m > chunk > 0:
+            vals, idxs = _chunked_queries(one, q, chunk, k)
+        else:
+            vals, idxs = one(q)
     if mt is DistanceType.L2SqrtExpanded:
         vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     elif mt is DistanceType.InnerProduct:
         # kernel min-selects -dot; report the raw inner products
         vals = jnp.where(jnp.isfinite(vals), -vals, -jnp.inf)
     return vals, idxs
+
+
+def _served(engine: str, out):
+    """Count one eager dispatch of ``engine`` in the serving metrics'
+    ``brute_force.search.<engine>`` counter and pass ``out`` through: the
+    record of which engine ``algo="auto"`` chose. Traced calls count
+    nothing (a trace is not a search)."""
+    if not in_jax_trace():
+        from ..serve import metrics as serve_metrics
+
+        serve_metrics.counter(f"brute_force.search.{engine}").inc()
+    return out
 
 
 @interop.auto_convert_output
@@ -630,7 +644,11 @@ def search(
     "scan" (composed-XLA streaming fallback, any metric), or "auto"
     (consults the ops.autotune measurement cache — populate it with
     ``tune_search`` — falling back to matmul/scan by metric; see
-    ops/autotune.py for why dispatch is measured, not hard-coded).
+    ops/autotune.py for why dispatch is measured, not hard-coded). Each
+    eager dispatch adds one to the serving metrics' counter
+    ``brute_force.search.<engine>`` of the engine that served it
+    (``pallas``, ``matmul``, ``scan`` or the filter's ``crossover``,
+    whose search of the compacted survivors counts its own engine too).
     ``precision``: MXU precision for the distance GEMM ("highest"/"default").
     ``workspace_mb``: matmul-engine distance-block budget override (else
     RAFT_TPU_MATMUL_WORKSPACE_MB, default 1024).
@@ -675,9 +693,10 @@ def search(
         if fd is not None and fd.use_brute:
             return filter_policy.crossover(
                 fd, "brute_force",
-                lambda: filter_policy.survivor_brute_dense(
-                    index.dataset, mt, q, k, filter, index.scales,
-                    index.metric_arg),
+                lambda: _served("crossover",
+                                filter_policy.survivor_brute_dense(
+                                    index.dataset, mt, q, k, filter,
+                                    index.scales, index.metric_arg)),
                 lambda: search(index, q, k, tile_size, filter, valid_rows,
                                algo, precision, workspace_mb))
 
@@ -713,15 +732,15 @@ def search(
         # GEMM engine (ops/guarded.py)
         return guarded_call(
             "brute_force.fused",
-            lambda: _search_pallas(index, q, k, filter, valid_rows,
-                                   precision),
-            lambda: _search_matmul(index, q, k, filter, valid_rows,
-                                   precision, workspace_mb))
+            lambda: _served("pallas", _search_pallas(
+                index, q, k, filter, valid_rows, precision)),
+            lambda: _served("matmul", _search_matmul(
+                index, q, k, filter, valid_rows, precision, workspace_mb)))
     if algo == "matmul":
         expects(expanded,
                 "algo='matmul' supports L2/cosine/IP, got %s", mt.name)
-        return _search_matmul(index, q, k, filter, valid_rows, precision,
-                              workspace_mb)
+        return _served("matmul", _search_matmul(
+            index, q, k, filter, valid_rows, precision, workspace_mb))
 
     tile = min(tile_size, round_up_to(n, 128))
     n_pad = round_up_to(n, tile)
@@ -786,7 +805,7 @@ def search(
     if scales_t is not None:
         xs.append(scales_t)
     (val, idx), _ = jax.lax.scan(step, init, tuple(xs))
-    return val, idx
+    return _served("scan", (val, idx))
 
 
 @interop.auto_convert_output
